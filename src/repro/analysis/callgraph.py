@@ -45,7 +45,7 @@ _TRACING_HOFS = {
     "jax.lax.map", "lax.map",
     "jax.lax.associative_scan", "lax.associative_scan",
     "jax.lax.custom_root", "lax.custom_root",
-    "shard_map", "jax.experimental.shard_map.shard_map",
+    "shard_map", "jax.shard_map",
     "jax.vmap", "vmap", "jax.pmap", "pmap",
     "jax.grad", "grad", "jax.value_and_grad", "value_and_grad",
     "jax.checkpoint", "jax.remat", "checkpoint", "remat",

@@ -65,8 +65,11 @@ def setup_transfers() -> Iterator[None]:
 
 def _compiled_name(msg: str) -> str:
     """The function name out of a "Compiling <name> with global shapes
-    and types [...]" record."""
-    return msg[len("Compiling "):].split(" with global shapes", 1)[0]
+    and types [...]" record. JAX 0.9 prints the name as ``jit(<name>)``."""
+    name = msg[len("Compiling "):].split(" with global shapes", 1)[0]
+    if name.startswith("jit(") and name.endswith(")"):
+        name = name[len("jit("):-1]
+    return name
 
 
 @dataclass

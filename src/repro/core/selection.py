@@ -428,19 +428,18 @@ def make_sharded_select_step(cfg: SelectorConfig, mesh, n_real: int,
     ``axis_name``; outputs are replicated and identical to
     :func:`select_device` on the unpadded inputs.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     n_shards = mesh.shape[axis_name]
     n_padded = n_real + (-n_real) % n_shards
     spec = P(axis_name)
-    body = shard_map(
+    body = jax.shard_map(
         partial(_shard_select, cfg=cfg, axis_name=axis_name, n_real=n_real,
                 use_pallas=use_pallas, interpret=interpret),
         mesh=mesh,
         in_specs=(P(), P(), spec, spec, spec),
         out_specs=(P(), P(), P()),
-        check_rep=False)
+        check_vma=False)
 
     @jax.jit
     def step(key, state, pop, predicted_cost_pct):

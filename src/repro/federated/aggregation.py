@@ -17,14 +17,18 @@ from repro.optim import SERVER_OPTIMIZERS, Optimizer, apply_updates
 PyTree = Any
 
 
+def weighted_sum(w: jnp.ndarray, d: jnp.ndarray) -> jnp.ndarray:
+    """``sum_j w[j] * d[j]`` over the leading client axis, at full f32
+    precision: the TPU's default dot would round both operands to
+    bfloat16."""
+    return jnp.tensordot(w.astype(d.dtype), d, axes=1,
+                         precision=jax.lax.Precision.HIGHEST)
+
+
 def weighted_delta(deltas: PyTree, weights: jnp.ndarray) -> PyTree:
     """deltas: pytree with leading client axis (C, ...); weights: (C,)."""
     w = weights / jnp.maximum(weights.sum(), 1e-9)
-
-    def avg(d):
-        return jnp.tensordot(w.astype(d.dtype), d, axes=1)
-
-    return jax.tree.map(avg, deltas)
+    return jax.tree.map(lambda d: weighted_sum(w, d), deltas)
 
 
 # --------------------------------------------------- non-finite quarantine

@@ -71,6 +71,7 @@ from repro.federated.aggregation import (
     server_update,
     tree_finite,
     weighted_delta,
+    weighted_sum,
     zero_nonfinite_rows,
 )
 from repro.federated.server import (
@@ -869,7 +870,6 @@ def _sharded_async_fused_runner(model_cfg, sel_cfg, energy_model,
     """Cached jitted sharded async-training runners (statics mirror
     :func:`_async_fused_runner` plus the mesh geometry). Returns the same
     segment-callable ``(fill, run, evaluate)`` triple."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     opt = make_server_optimizer(server_opt, server_lr)
@@ -989,7 +989,7 @@ def _sharded_async_fused_runner(model_cfg, sel_cfg, energy_model,
         wn_sl = jax.lax.dynamic_slice_in_dim(wn, sl, b_per)
         agg = jax.tree.map(
             lambda d: jax.lax.psum(
-                jnp.tensordot(wn_sl.astype(d.dtype), d, axes=1), axis_name),
+                weighted_sum(wn_sl, d), axis_name),
             deltas)
         su = jax.lax.all_gather(
             stat_utility(per_sample, w_sl), axis_name).reshape(-1)
@@ -1025,15 +1025,15 @@ def _sharded_async_fused_runner(model_cfg, sel_cfg, energy_model,
         }
         return pop, st, astate, slot_rank, agg, out
 
-    fill_smapped = shard_map(
+    fill_smapped = jax.shard_map(
         fill_body, mesh=mesh,
         in_specs=(rep, rep, astate_spec, spec, spec, spec, spec, spec),
-        out_specs=(rep, astate_spec, rep, rep, spec), check_rep=False)
-    smapped = shard_map(
+        out_specs=(rep, astate_spec, rep, rep, spec), check_vma=False)
+    smapped = jax.shard_map(
         train_body, mesh=mesh,
         in_specs=(rep, rep, astate_spec, spec, spec, spec, spec, spec,
                   spec, spec, spec, rep, rep, rep, rep, rep),
-        out_specs=(spec, rep, astate_spec, spec, rep, rep), check_rep=False)
+        out_specs=(spec, rep, astate_spec, spec, rep, rep), check_vma=False)
     shard = NamedSharding(mesh, spec)
 
     @jax.jit

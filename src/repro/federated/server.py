@@ -46,6 +46,7 @@ from repro.federated.aggregation import (
     server_update,
     tree_finite,
     weighted_delta,
+    weighted_sum,
     zero_nonfinite_rows,
 )
 from repro.federated.faults import (
@@ -1139,7 +1140,6 @@ def _sharded_fused_runner(model_cfg: ResNetConfig, sel_cfg: SelectorConfig,
     """Cached jitted sharded fused training scan (statics mirror
     :func:`_fused_runner` plus the mesh geometry). Returns the same
     segment-callable ``(run, evaluate)`` pair as :func:`_fused_runner`."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     opt = make_server_optimizer(server_opt, server_lr)
@@ -1254,7 +1254,7 @@ def _sharded_fused_runner(model_cfg: ResNetConfig, sel_cfg: SelectorConfig,
         wn_sl = jax.lax.dynamic_slice_in_dim(wn, sl, n_per)
         agg = jax.tree.map(
             lambda d: jax.lax.psum(
-                jnp.tensordot(wn_sl.astype(d.dtype), d, axes=1), axis_name),
+                weighted_sum(wn_sl, d), axis_name),
             deltas)
         # replicated per-slot stats (all_gather in shard order == slot order)
         su = jax.lax.all_gather(
@@ -1289,11 +1289,11 @@ def _sharded_fused_runner(model_cfg: ResNetConfig, sel_cfg: SelectorConfig,
         }
         return pop, st, agg, stats, ledger
 
-    smapped = shard_map(
+    smapped = jax.shard_map(
         body, mesh=mesh,
         in_specs=(rep, rep, rep, rep, spec, rep, spec, spec, spec, spec,
                   spec, spec) + ((spec,) if faulty else ()),
-        out_specs=(spec, rep, rep, rep, rep), check_rep=False)
+        out_specs=(spec, rep, rep, rep, rep), check_vma=False)
 
     @jax.jit
     def evaluate(params, test_x, test_y):

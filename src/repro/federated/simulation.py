@@ -641,7 +641,6 @@ def _sharded_scanned_runner(sel_cfg: SelectorConfig,
     """Cached jitted sharded scan over a caller-supplied (R, 2) key array.
     The hoisted cost table is a run argument (not a static), so one
     compilation serves any population with the same shape/config."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     n_shards = mesh.shape[axis_name]
@@ -675,11 +674,11 @@ def _sharded_scanned_runner(sel_cfg: SelectorConfig,
         return pop, st, out
 
     stream_specs = (spec,) if faulty else ()
-    smapped = shard_map(body, mesh=mesh,
-                        in_specs=(P(), P(), spec, spec, spec, spec)
-                        + stream_specs,
-                        out_specs=(spec, P(), P()),
-                        check_rep=False)
+    smapped = jax.shard_map(body, mesh=mesh,
+                            in_specs=(P(), P(), spec, spec, spec, spec)
+                            + stream_specs,
+                            out_specs=(spec, P(), P()),
+                            check_vma=False)
 
     @jax.jit
     def run(keys, pop, st, t_total, cost):
@@ -1473,7 +1472,6 @@ def make_sharded_async_engine(sel_cfg: SelectorConfig,
     outputs are index-for-index identical to the single-device engine on
     the unpadded population (pad clients are dead and never selected).
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     if axis_name is None:
@@ -1488,15 +1486,15 @@ def make_sharded_async_engine(sel_cfg: SelectorConfig,
                                   server_clock=P(), server_version=P(),
                                   spent_j=P(), exhausted_round=P())
 
-    fill_body = shard_map(
+    fill_body = jax.shard_map(
         partial(_shard_async_fill, fill_cfg=fill_cfg, axis_name=axis_name,
                 n_real=n_real, use_pallas=use_pallas, interpret=interpret,
                 energy_budget_j=energy_budget_j),
         mesh=mesh,
         in_specs=(P(), P(), astate_spec, spec, spec, spec, spec),
         out_specs=(P(), astate_spec, P(), P()),
-        check_rep=False)
-    step_body = shard_map(
+        check_vma=False)
+    step_body = jax.shard_map(
         partial(_shard_async_step, refill_cfg=refill_cfg,
                 buffer_size=buffer_size, staleness_power=staleness_power,
                 energy_model=energy_model, deadline_s=deadline_s,
@@ -1506,7 +1504,7 @@ def make_sharded_async_engine(sel_cfg: SelectorConfig,
         mesh=mesh,
         in_specs=(P(), P(), astate_spec, spec, spec, spec, spec, P()),
         out_specs=(spec, P(), astate_spec, P(), P(), P()),
-        check_rep=False)
+        check_vma=False)
 
     def _bits(key):
         # prefix-stable sharded rank bits (partitionable threefry): the

@@ -6,7 +6,8 @@ XLA locks the host device count at first jax init, so any CLI that offers
 jax. This module therefore imports no jax and lives directly under the
 ``repro`` namespace package (no package ``__init__`` runs on import);
 call :func:`force_host_device_count_from_argv` at the very top of an
-entrypoint, ahead of the first jax import.
+entrypoint, ahead of the first jax import. The flag shapes only XLA's CPU
+platform: on a TPU host the sharded engines see the real chips.
 """
 from __future__ import annotations
 

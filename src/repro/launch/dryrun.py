@@ -78,9 +78,9 @@ def lower_one(arch: str, shape_name: str, multi_pod: bool,
     # dispatch still reshards experts over `model` (role used by moe_apply).
     act_model = None if strategy == "fsdp" else "model"
 
-    # `with mesh:` (not jax.set_mesh, which the installed jax predates)
-    # makes bare-PartitionSpec sharding constraints resolvable in-trace
-    with mesh, activation_axes(
+    # jax.set_mesh makes bare-PartitionSpec sharding constraints
+    # resolvable in-trace
+    with jax.set_mesh(mesh), activation_axes(
             batch=strategy_batch_axes(mesh, strategy), model=act_model,
             gather_weights=(strategy in ("fsdp", "ep_fsdp"))):
         if shape.mode == "train":

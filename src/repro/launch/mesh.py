@@ -15,19 +15,22 @@ import numpy as np
 
 
 def make_production_mesh(*, multi_pod: bool = False):
-    """v5e pod mesh: 16x16 = 256 chips per pod; 2 pods = 512 chips.
-
-    (No ``axis_types``: the installed jax predates ``jax.sharding.AxisType``
-    and its default — auto axes — is what these meshes used anyway.)
-    """
+    """v5e pod mesh: 16x16 = 256 chips per pod; 2 pods = 512 chips."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh():
     """Single-device mesh for CPU smoke runs (axes sized 1)."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return _auto_mesh((1, 1), ("data", "model"))
+
+
+def _auto_mesh(shape, axes):
+    """A mesh whose axes the compiler partitions (``make_mesh`` defaults
+    to explicit axes, which would carry shardings in the types)."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_client_mesh(n_devices: Optional[int] = None):

@@ -3,6 +3,12 @@
 Pure-JAX functional ResNet (He et al., CVPR'16) over 1x32x32 mel-like inputs,
 35 classes, sized for the edge-device simulation (matches the paper's
 ResNet-on-Google-Speech setup at the FedScale scale).
+
+Convolutions and the head run at full f32 precision. The TPU's default
+rounds f32 operands to bfloat16, and the rounding then depends on how
+each compiled program orders its f32 sums: the host loop and the fused
+engines would train different models from one seed, and the
+utility-ranked selection that follows would pick different clients.
 """
 from __future__ import annotations
 
@@ -12,6 +18,7 @@ import jax
 import jax.numpy as jnp
 
 Params = Dict[str, Any]
+F32 = jax.lax.Precision.HIGHEST
 
 
 def _conv_init(key, k, cin, cout):
@@ -22,7 +29,7 @@ def _conv_init(key, k, cin, cout):
 def conv2d(x, w, stride=1):
     return jax.lax.conv_general_dilated(
         x, w, (stride, stride), "SAME",
-        dimension_numbers=("NHWC", "HWIO", "NHWC"))
+        dimension_numbers=("NHWC", "HWIO", "NHWC"), precision=F32)
 
 
 def group_norm(x, gamma, beta, groups=8, eps=1e-5):
@@ -87,7 +94,7 @@ def resnet_forward(cfg, p: Params, x):
                 r = conv2d(r, blk["proj"], stride=s)
             h = jax.nn.relu(r + h2)
     h = h.mean(axis=(1, 2))
-    return h @ p["head_w"] + p["head_b"]
+    return jnp.dot(h, p["head_w"], precision=F32) + p["head_b"]
 
 
 def resnet_loss(cfg, p: Params, batch):

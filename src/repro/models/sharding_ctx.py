@@ -26,25 +26,10 @@ _AXES = {"batch": None, "model": None, "gather_weights": False}
 
 
 def _ambient_mesh():
-    """The mesh whose axes bare-PartitionSpec constraints resolve against.
-
-    Newer jax exposes ``jax.sharding.get_abstract_mesh()`` (set via
-    ``jax.set_mesh``); the installed 0.4-era jax instead carries the mesh
-    entered with ``with mesh:`` in ``thread_resources`` — check both so the
-    launchers work on either API. Returns None when no mesh is active.
-    """
-    getter = getattr(jax.sharding, "get_abstract_mesh", None)
-    if getter is not None:
-        mesh = getter()
-        if mesh is not None and not mesh.empty:
-            return mesh
-    # fall through even when the getter exists: `with mesh:` only sets
-    # thread_resources, and the abstract mesh defaults to empty
-    from jax._src import mesh as mesh_lib
-    mesh = mesh_lib.thread_resources.env.physical_mesh
-    if mesh is not None and not mesh.empty:
-        return mesh
-    return None
+    """The mesh whose axes bare-PartitionSpec constraints resolve against:
+    the one the launcher entered with ``jax.set_mesh``, or None."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return None if mesh.empty else mesh
 
 
 def set_axes(batch: AxisName = None, model: AxisName = None,
