@@ -20,16 +20,25 @@ width (``paper_resnet_speech.CONFIG``, batch 20, lr 0.05, YoGi, 10 local
 steps, K = 100) over FedScale's 2,618 Google Speech Commands clients with
 64 synthetic samples each; weights and data come from seed 0.
 
-Comparisons. Integer trajectories (``cum_dropouts``, ``participation``,
-selected indices) must be identical. Float statistics must agree within
-``FLOAT_RTOL`` (``mean_battery``, ``train_loss``) and ``ACC_ATOL_SAMPLES``
-eval samples (``test_acc``). The model and the aggregation run at full
-f32 precision, but the reference and the device engine are different
-compiled programs that order their f32 sums differently. Those last-ulp
-differences (relative 2**-24) grow through 10 local SGD steps per client
-and a server update per round; over a few rounds they stay orders of
-magnitude below 1e-3 of the loss, while a near-tied eval sample's argmax
-can still flip.
+Comparisons. Selected indices, ``cum_dropouts`` and ``participation``
+must be identical (and for the sharded engine also ``round_duration``
+and ``wall_hours``, as ``launch/sharded_check.py --train`` requires).
+``mean_battery``, ``fairness`` and ``test_acc`` must agree within
+``FLOAT_ATOL``, that check's limit, in every round, and so must
+``train_loss`` in round 1, where both engines start from the same
+parameters. From round 2 on ``train_loss`` must agree within
+``TRAIN_LOSS_DRIFT_ATOL``. The engines run the same f32 arithmetic as
+differently shaped programs (the host loop over the clients that
+succeeded, the sharded engine over K / 4 slots per chip), and on the
+TPU a cohort's local SGD is not bitwise invariant to that shape even
+at highest precision. ``precision_probe.py`` measured it on a v5e:
+the width split moves round-1 train_loss by 2.05e-05, but YoGi's first
+step, -lr * d / (|d| + 1e-3), turns a last-bit difference into up to
+1,000 times as much, and a run started one ulp away ends round 2 with
+train_loss 1.26e-03 apart. The drift limit is twice that reading; a
+bug of the kind it guards against (one shard's deltas left out of the
+aggregate) moved train_loss by 0.42 in round 2 of a cut-down CPU run
+(K = 8, 2 local steps).
 
 Timings printed here are smoke timings of one cold process (compile
 seconds, and rounds per second of a second, warm call that includes its
@@ -49,8 +58,8 @@ import time
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
                                 "src"))
 
-FLOAT_RTOL = 1e-3
-ACC_ATOL_SAMPLES = 2
+FLOAT_ATOL = 5e-4
+TRAIN_LOSS_DRIFT_ATOL = 2.5e-3
 SELECTION_MODEL_BYTES = 85e6   # ResNet-34-class device workload
 SELECTION_STEPS, SELECTION_BATCH = 400, 20
 
@@ -88,30 +97,36 @@ def report(label, rounds, cold_s, warm_s):
           f"{warm_s:.2f} s warm, {cold_s:.2f} s cold)", flush=True)
 
 
-def compare_histories(label, ref, got, eval_samples):
-    """Integer trajectories exact; float statistics within the module's
-    stated tolerance. Prints the largest float difference per field."""
+def compare_histories(label, ref, got, exact=("cum_dropouts",
+                                              "participation")):
+    """``exact`` fields identical; float statistics within the module's
+    stated limits. Prints train_loss's difference per round and the
+    largest difference of the other float fields."""
     import numpy as np
 
     check(len(ref.round) == len(got.round),
           f"{label}: {len(ref.round)} vs {len(got.round)} rounds")
-    for f in ("cum_dropouts", "participation"):
+    for f in exact:
         a, b = getattr(ref, f), getattr(got, f)
         check(a == b, f"{label}: {f} differs: {a} vs {b}")
-    diffs = []
-    for f in ("mean_battery", "train_loss", "test_acc"):
+    check(abs(ref.init_acc - got.init_acc) <= FLOAT_ATOL,
+          f"{label}: init_acc {ref.init_acc} vs {got.init_acc}")
+    diffs = {}
+    for f in ("mean_battery", "fairness", "test_acc", "train_loss"):
         a = np.asarray(getattr(ref, f), np.float64)
         b = np.asarray(getattr(got, f), np.float64)
-        if f == "test_acc":
-            np.testing.assert_allclose(
-                b, a, rtol=0, atol=ACC_ATOL_SAMPLES / eval_samples,
-                err_msg=f"{label}: {f}")
-        else:
-            np.testing.assert_allclose(b, a, rtol=FLOAT_RTOL,
-                                       err_msg=f"{label}: {f}")
-        diffs.append(f"{f} {np.max(np.abs(a - b)):.3g}")
-    print(f"  {label}: integer fields identical; max |diff|: "
-          + ", ".join(diffs), flush=True)
+        atol = np.full(a.shape, FLOAT_ATOL)
+        if f == "train_loss":
+            atol[1:] = TRAIN_LOSS_DRIFT_ATOL
+        d = np.abs(a - b)
+        check(bool(np.all(d <= atol)),
+              f"{label}: {f} |diff| per round {d.tolist()} over {atol}")
+        diffs[f] = d
+    print(f"  {label}: {', '.join(exact)} identical; max |diff|: "
+          + ", ".join(f"{f} {np.max(d):.3g}" for f, d in diffs.items()
+                      if f != "train_loss")
+          + "; train_loss |diff| per round: "
+          + ", ".join(f"{x:.3g}" for x in diffs["train_loss"]), flush=True)
 
 
 def assert_same_history(label, a, b):
@@ -130,8 +145,7 @@ def phase_sync_training():
     host, host_s = timed(lambda: run_fl(cfg, engine="host"))
     print(f"  [smoke timing] sync training, host loop: {host_s:.2f} s cold",
           flush=True)
-    compare_histories("sync host vs scanned", host, scanned,
-                      cfg.eval_samples)
+    compare_histories("sync host vs scanned", host, scanned)
     print(f"  final test_acc {scanned.test_acc[-1]:.4f}, train_loss "
           f"{scanned.train_loss[-1]:.4f}, participation "
           f"{scanned.participation}", flush=True)
@@ -155,8 +169,7 @@ def phase_async_training():
     host, host_s = timed(lambda: run_fl(cfg, engine="host"))
     print(f"  [smoke timing] async training, host event loop: {host_s:.2f} s "
           f"cold", flush=True)
-    compare_histories("async host vs event scan", host, fused,
-                      cfg.eval_samples)
+    compare_histories("async host vs event scan", host, fused)
 
 
 def fleet(key, n):
@@ -282,7 +295,8 @@ def phase_sharded_training():
     _, warm = timed(lambda: run_fl(cfg, engine="sharded"))
     report("sharded training", cfg.rounds, cold, warm)
     compare_histories("sharded vs scanned training", scanned, sharded,
-                      cfg.eval_samples)
+                      exact=("cum_dropouts", "participation",
+                             "round_duration", "wall_hours"))
 
 
 ONE_CHIP = (("sync training", phase_sync_training),

@@ -15,25 +15,19 @@ from __future__ import annotations
 
 import os
 
-ENV_VAR = "JAX_COMPILATION_CACHE_DIR"
-CHECKOUT_CACHE_DIR = os.path.join(
+_CHECKOUT_DIR = os.path.join(
     os.path.dirname(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__)))), ".jax_cache")
-
-
-def compile_cache_dir() -> str:
-    """Where compiled executables go: the environment's directory when
-    ``JAX_COMPILATION_CACHE_DIR`` is set, else the checkout's own."""
-    return os.environ.get(ENV_VAR) or CHECKOUT_CACHE_DIR
 
 
 def enable_compile_cache() -> str:
     """Turn the persistent cache on and return its directory. With
     ``JAX_COMPILATION_CACHE_DIR`` set, JAX already uses it and no other
-    directory is configured."""
+    directory is configured; otherwise the checkout's own is."""
     import jax
 
-    path = compile_cache_dir()
-    if not os.environ.get(ENV_VAR):
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = _CHECKOUT_DIR
         jax.config.update("jax_compilation_cache_dir", path)
     return path

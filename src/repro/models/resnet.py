@@ -4,11 +4,12 @@ Pure-JAX functional ResNet (He et al., CVPR'16) over 1x32x32 mel-like inputs,
 35 classes, sized for the edge-device simulation (matches the paper's
 ResNet-on-Google-Speech setup at the FedScale scale).
 
-Convolutions and the head run at full f32 precision. The TPU's default
-rounds f32 operands to bfloat16, and the rounding then depends on how
-each compiled program orders its f32 sums: the host loop and the fused
-engines would train different models from one seed, and the
-utility-ranked selection that follows would pick different clients.
+Convolutions and the head run at full f32 precision. At the TPU's
+default, which rounds f32 operands to bfloat16, a cohort's local SGD
+depends on the width the cohort is compiled at (on a v5e by up to 10%
+of a client's mean loss, ``precision_probe.py``): the host loop, which
+trains the clients that succeeded, and the fused engines, which train
+every slot, would rank clients differently and select different ones.
 """
 from __future__ import annotations
 

@@ -18,9 +18,8 @@ def restore_cache_dir():
 
 
 def test_env_var_wins(monkeypatch, tmp_path, restore_cache_dir):
-    monkeypatch.setenv(compile_cache.ENV_VAR, str(tmp_path))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
     before = jax.config.jax_compilation_cache_dir
-    assert compile_cache.compile_cache_dir() == str(tmp_path)
     assert compile_cache.enable_compile_cache() == str(tmp_path)
     assert compile_cache.enable_compile_cache() == str(tmp_path)
     # JAX reads the variable itself; the helper configures no other dir
@@ -28,9 +27,8 @@ def test_env_var_wins(monkeypatch, tmp_path, restore_cache_dir):
 
 
 def test_fixed_checkout_dir(monkeypatch, restore_cache_dir):
-    monkeypatch.delenv(compile_cache.ENV_VAR, raising=False)
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
     expected = os.path.join(REPO, ".jax_cache")
-    assert compile_cache.compile_cache_dir() == expected
     assert compile_cache.enable_compile_cache() == expected
     assert compile_cache.enable_compile_cache() == expected
     assert jax.config.jax_compilation_cache_dir == expected
