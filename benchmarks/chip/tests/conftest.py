@@ -1,0 +1,11 @@
+"""The benchmark's own tests run on the CPU: its modules and the program
+are imported from the checkout, as ``run.py`` imports them."""
+import os
+import sys
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+BENCH = os.path.dirname(HERE)
+ROOT = os.path.dirname(os.path.dirname(BENCH))
+for p in (os.path.join(ROOT, "src"), BENCH):
+    if p not in sys.path:
+        sys.path.insert(0, p)
