@@ -198,6 +198,7 @@ def compute_scores(cfg: SelectorConfig, state: SelectorState,
     return _mix_scores(cfg, a, b, valid, mask, ucb, mode)
 
 
+@jax.named_scope("select")
 def _device_select(key, cfg: SelectorConfig, state: SelectorState,
                    pop: ClientPopulation, predicted_cost_pct,
                    use_pallas: bool, interpret: bool):

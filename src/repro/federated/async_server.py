@@ -60,6 +60,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import spans
 from repro.analysis.runtime import setup_transfers
 from repro.checkpoint import load_engine_checkpoint
 from repro.core import SelectorState, jains_index, stat_utility
@@ -85,7 +86,9 @@ from repro.federated.server import (
     _recharge_step,
     _record_test_acc,
     _run_fused_elastic,
+    _test_accuracy,
     _train_meta,
+    _untrained_acc,
 )
 from repro.federated.simulation import (
     AsyncEventState,
@@ -99,7 +102,6 @@ from repro.federated.simulation import (
     make_async_round_engine,
     round_cost_table,
 )
-from repro.models.resnet import resnet_forward
 
 _I32_MAX = np.iinfo(np.int32).max
 
@@ -338,8 +340,9 @@ def run_fl_async(cfg: FLConfig, verbose: bool = False,
     """
     _check_async_cfg(cfg)
     buffer_size, max_concurrency, ring_size = _async_geometry(cfg)
-    (kloop, data, test, params, opt_state, pop, sim_steps, up_bytes,
-     energy_model, model_bytes) = _fused_setup(cfg)
+    with spans.span("fl.setup"):
+        (kloop, data, test, params, opt_state, pop, sim_steps, up_bytes,
+         energy_model, model_bytes) = _fused_setup(cfg)
     opt = make_server_optimizer(cfg.server_opt, cfg.server_lr)
     sel_state = SelectorState.create(cfg.selector).canonical()
     astate = AsyncEventState.create(pop.n)
@@ -370,8 +373,7 @@ def run_fl_async(cfg: FLConfig, verbose: bool = False,
 
     @jax.jit
     def test_acc_fn(p):
-        logits = resnet_forward(cfg.model, p, test["x"])
-        return (jnp.argmax(logits, -1) == test["y"]).mean()
+        return _test_accuracy(cfg.model, p, test["x"], test["y"])
 
     meta = _async_train_meta(cfg, "train-async-host")
     ck = _make_checkpointer(cfg.checkpoint_path, cfg.checkpoint_every,
@@ -646,8 +648,7 @@ def _async_fused_runner(model_cfg, sel_cfg, energy_model,
 
     @jax.jit
     def evaluate(params, test_x, test_y):
-        logits = resnet_forward(model_cfg, params, test_x)
-        return (jnp.argmax(logits, -1) == test_y).mean()
+        return _test_accuracy(model_cfg, params, test_x, test_y)
 
     @jax.jit
     def fill(kloop, params, opt_state, pop, st, last_acc):
@@ -670,8 +671,7 @@ def _async_fused_runner(model_cfg, sel_cfg, energy_model,
         n = carry[2].n
 
         def eval_acc(p):
-            logits = resnet_forward(model_cfg, p, test_x)
-            return (jnp.argmax(logits, -1) == test_y).mean()
+            return _test_accuracy(model_cfg, p, test_x, test_y)
 
         def scan_step(carry, do_eval):
             (params, opt_state, pop, st, astate, ring, slot_rank, krech,
@@ -807,20 +807,24 @@ def run_fl_async_scanned(cfg: FLConfig, verbose: bool = False,
     trajectory under ``"traj"``.
     """
     _check_async_cfg(cfg)
-    with setup_transfers():  # one-time host->device materialization
+    # one-time host->device materialization
+    with setup_transfers(), spans.span("fl.setup"):
         (kloop, data, test, params, opt_state, pop, sim_steps, up_bytes,
          energy_model, model_bytes) = _fused_setup(cfg)
-        fill, run, evaluate = _async_fused_runner(
-            cfg.model, *_async_runner_statics(cfg, sim_steps, energy_model,
-                                              model_bytes, up_bytes),
-            _auto_pallas(cfg.n_clients, None),
-            jax.default_backend() != "tpu")
-        st = SelectorState.create(cfg.selector).canonical()
-        acc0 = evaluate(params, test["x"], test["y"])
+        with spans.span("fl.setup.runner"):
+            fill, run, evaluate = _async_fused_runner(
+                cfg.model, *_async_runner_statics(cfg, sim_steps,
+                                                  energy_model, model_bytes,
+                                                  up_bytes),
+                _auto_pallas(cfg.n_clients, None),
+                jax.default_backend() != "tpu")
+            st = SelectorState.create(cfg.selector).canonical()
+        acc0, init_acc = _untrained_acc(evaluate, params, test)
         carry0, _idx0, _chosen0 = fill(kloop, params, opt_state, pop, st,
                                        acc0)
     hist = _run_fused_elastic(
-        cfg, run, carry0, (data["x"], data["y"], test["x"], test["y"]),
+        cfg, run, carry0, init_acc,
+        (data["x"], data["y"], test["x"], test["y"]),
         {"pop_template": pop,
          "restore": lambda state: tuple(state[k] for k in _ASYNC_CARRY)},
         lambda carry: dict(zip(_ASYNC_CARRY, carry)),
@@ -1038,8 +1042,7 @@ def _sharded_async_fused_runner(model_cfg, sel_cfg, energy_model,
 
     @jax.jit
     def evaluate(params, test_x, test_y):
-        logits = resnet_forward(model_cfg, params, test_x)
-        return (jnp.argmax(logits, -1) == test_y).mean()
+        return _test_accuracy(model_cfg, params, test_x, test_y)
 
     @jax.jit
     def fill(kloop, params, opt_state, pop, st, last_acc, t_total, cost):
@@ -1060,8 +1063,7 @@ def _sharded_async_fused_runner(model_cfg, sel_cfg, energy_model,
     @functools.partial(jax.jit, donate_argnums=(1,))
     def run(do_eval, carry, data_x, data_y, test_x, test_y, t_total, cost):
         def eval_acc(p):
-            logits = resnet_forward(model_cfg, p, test_x)
-            return (jnp.argmax(logits, -1) == test_y).mean()
+            return _test_accuracy(model_cfg, p, test_x, test_y)
 
         def scan_step(carry, do_eval):
             (params, opt_state, pop, st, astate, ring, slot_rank, krech,
@@ -1122,7 +1124,8 @@ def run_fl_async_sharded(cfg: FLConfig, verbose: bool = False, mesh=None,
     if mesh is None:
         mesh = make_client_mesh(n_shards)
     axis_name = mesh.axis_names[0]
-    with setup_transfers():  # one-time host->device materialization
+    # one-time host->device materialization
+    with setup_transfers(), spans.span("fl.setup"):
         (kloop, data, test, params, opt_state, pop, sim_steps, up_bytes,
          energy_model, model_bytes) = _fused_setup(cfg)
         n_real = pop.n
@@ -1139,16 +1142,19 @@ def run_fl_async_sharded(cfg: FLConfig, verbose: bool = False, mesh=None,
             return jax.device_put(a, sharding)
 
         data_x, data_y = pad_clients(data["x"]), pad_clients(data["y"])
-        t_total, cost = round_cost_table(pop, energy_model, model_bytes,
-                                         sim_steps, cfg.batch_size,
-                                         up_bytes, sharding=sharding)
-        fill, run, evaluate = _sharded_async_fused_runner(
-            cfg.model, *_async_runner_statics(cfg, sim_steps, energy_model,
-                                              model_bytes, up_bytes),
-            _auto_pallas(n_real, None), jax.default_backend() != "tpu",
-            mesh, n_real, axis_name)
-        st = SelectorState.create(cfg.selector).canonical()
-        acc0 = evaluate(params, test["x"], test["y"])
+        with spans.span("fl.setup.cost_table"):
+            t_total, cost = round_cost_table(pop, energy_model, model_bytes,
+                                             sim_steps, cfg.batch_size,
+                                             up_bytes, sharding=sharding)
+        with spans.span("fl.setup.runner"):
+            fill, run, evaluate = _sharded_async_fused_runner(
+                cfg.model, *_async_runner_statics(cfg, sim_steps,
+                                                  energy_model, model_bytes,
+                                                  up_bytes),
+                _auto_pallas(n_real, None), jax.default_backend() != "tpu",
+                mesh, n_real, axis_name)
+            st = SelectorState.create(cfg.selector).canonical()
+        acc0, init_acc = _untrained_acc(evaluate, params, test)
         carry0, _idx0, _chosen0 = fill(kloop, params, opt_state, pop, st,
                                        acc0, t_total, cost)
     n_padded = pop.n
@@ -1180,7 +1186,7 @@ def run_fl_async_sharded(cfg: FLConfig, verbose: bool = False, mesh=None,
         return s
 
     hist = _run_fused_elastic(
-        cfg, run, carry0,
+        cfg, run, carry0, init_acc,
         (data_x, data_y, test["x"], test["y"], t_total, cost),
         {"pop_template": pop0, "restore": _restore,
          "overrides": {"astate": AsyncEventState.create(n_real),

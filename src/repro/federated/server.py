@@ -37,6 +37,7 @@ from repro.core.selection import (
     _rank_bits,
     _slot_gather,
 )
+from repro import spans
 from repro.analysis.runtime import setup_transfers
 from repro.checkpoint import load_engine_checkpoint, segment_bounds
 from repro.data import label_restricted_partition, make_test_set
@@ -256,6 +257,7 @@ def _cohort_train_fn(model_cfg, local_steps: int, batch_size: int, lr: float,
         _, per_sample = resnet_loss(model_cfg, new_params, {"x": x, "y": y})
         return delta, per_sample, losses.mean()
 
+    @jax.named_scope("cohort_sgd")
     def cohort(params, xs, ys, keys):
         return jax.vmap(one_client, in_axes=(params_axis, 0, 0, 0))(
             params, xs, ys, keys)
@@ -271,6 +273,14 @@ def _local_train_fn(model_cfg, local_steps: int, batch_size: int, lr: float,
     return jax.jit(_cohort_train_fn(
         model_cfg, local_steps, batch_size, lr, fedprox_mu, compression,
         compression_sparsity, params_axis))
+
+
+@jax.named_scope("eval")
+def _test_accuracy(model_cfg, params, x, y):
+    """Share of the test samples ``x`` the model labels ``y``: every
+    engine's eval, inside their steps and out."""
+    logits = resnet_forward(model_cfg, params, x)
+    return (jnp.argmax(logits, -1) == y).mean()
 
 
 @dataclass
@@ -396,6 +406,7 @@ def _train_meta(cfg: FLConfig, family: str) -> Dict[str, Any]:
     }
 
 
+@spans.span("run_fl")
 def run_fl(cfg: FLConfig, verbose: bool = False,
            mode: str = "auto", engine: str = "auto") -> FLHistory:
     """Run the full FL experiment (REAL training).
@@ -485,8 +496,7 @@ def run_fl(cfg: FLConfig, verbose: bool = False,
 
     @jax.jit
     def test_acc_fn(p):
-        logits = resnet_forward(cfg.model, p, test["x"])
-        return (jnp.argmax(logits, -1) == test["y"]).mean()
+        return _test_accuracy(cfg.model, p, test["x"], test["y"])
 
     # the round-invariant (time, cost) table: both columns depend only on
     # immutable population fields, so the per-round predicted_round_cost_pct
@@ -760,16 +770,14 @@ def _fused_runner(model_cfg: ResNetConfig, sel_cfg: SelectorConfig,
 
     @jax.jit
     def evaluate(params, test_x, test_y):
-        logits = resnet_forward(model_cfg, params, test_x)
-        return (jnp.argmax(logits, -1) == test_y).mean()
+        return _test_accuracy(model_cfg, params, test_x, test_y)
 
     @functools.partial(jax.jit, donate_argnums=(1,))
     def run(do_eval, carry, data_x, data_y, test_x, test_y, t_total, cost):
         n = carry[2].n
 
         def eval_acc(p):
-            logits = resnet_forward(model_cfg, p, test_x)
-            return (jnp.argmax(logits, -1) == test_y).mean()
+            return _test_accuracy(model_cfg, p, test_x, test_y)
 
         def scan_step(carry, do_eval):
             params, opt_state, pop, st, kloop, last_acc, ledger = carry
@@ -835,16 +843,19 @@ def _fused_runner(model_cfg: ResNetConfig, sel_cfg: SelectorConfig,
             # row (0 * nan == nan), renormalize over survivors, and gate
             # the whole update on the aggregate staying finite — identical
             # to the host loop's quarantine block
-            finite = finite_rows(deltas)
-            good = mask & finite
-            w = jnp.where(good, pop.n_samples[idx].astype(jnp.float32), 0.0)
-            agg = weighted_delta(zero_nonfinite_rows(deltas, finite), w)
-            new_params, new_opt = server_update(params, agg, opt, opt_state)
-            ok = good.any() & tree_finite(agg)
-            params = jax.tree.map(
-                lambda a, b: jnp.where(ok, a, b), new_params, params)
-            opt_state = jax.tree.map(
-                lambda a, b: jnp.where(ok, a, b), new_opt, opt_state)
+            with jax.named_scope("aggregate"):
+                finite = finite_rows(deltas)
+                good = mask & finite
+                w = jnp.where(good, pop.n_samples[idx].astype(jnp.float32),
+                              0.0)
+                agg = weighted_delta(zero_nonfinite_rows(deltas, finite), w)
+                new_params, new_opt = server_update(params, agg, opt,
+                                                    opt_state)
+                ok = good.any() & tree_finite(agg)
+                params = jax.tree.map(
+                    lambda a, b: jnp.where(ok, a, b), new_params, params)
+                opt_state = jax.tree.map(
+                    lambda a, b: jnp.where(ok, a, b), new_opt, opt_state)
             su = stat_utility(per_sample, w)
             pop = scatter_stat_util(pop, idx, good, su)
             last_acc = jax.lax.cond(do_eval, eval_acc,
@@ -886,22 +897,27 @@ def _fused_runner(model_cfg: ResNetConfig, sel_cfg: SelectorConfig,
 def _fused_setup(cfg: FLConfig):
     """Shared data/model/population setup for the fused training engines —
     the exact :func:`run_fl` preamble (same key split, same builders), so
-    engine trajectories start from identical state."""
+    engine trajectories start from identical state. Callers open the
+    ``fl.setup`` span this records its first three children in."""
     key = jax.random.PRNGKey(cfg.seed)
     kpop, kdata, kmodel, ktest, kloop = jax.random.split(key, 5)
-    data = label_restricted_partition(
-        kdata, cfg.n_clients, cfg.samples_per_client, cfg.n_classes,
-        cfg.labels_per_client, cfg.input_hw, noise=cfg.data_noise)
-    test = make_test_set(ktest, cfg.eval_samples, cfg.n_classes, cfg.input_hw,
-                         noise=cfg.data_noise)
-    params = init_resnet(kmodel, cfg.model)
-    n_params = sum(x.size for x in jax.tree.leaves(params))
-    model_bytes = (cfg.sim_model_bytes if cfg.sim_model_bytes is not None
-                   else n_params * 4.0)
-    opt = make_server_optimizer(cfg.server_opt, cfg.server_lr)
-    opt_state = opt.init(params)
-    pop, sim_steps, up_bytes, energy_model = _engine_setup(cfg, kpop,
-                                                           model_bytes)
+    with spans.span("fl.setup.data"):
+        data = label_restricted_partition(
+            kdata, cfg.n_clients, cfg.samples_per_client, cfg.n_classes,
+            cfg.labels_per_client, cfg.input_hw, noise=cfg.data_noise)
+        test = make_test_set(ktest, cfg.eval_samples, cfg.n_classes,
+                             cfg.input_hw, noise=cfg.data_noise)
+    with spans.span("fl.setup.model"):
+        params = init_resnet(kmodel, cfg.model)
+        n_params = sum(x.size for x in jax.tree.leaves(params))
+        model_bytes = (cfg.sim_model_bytes
+                       if cfg.sim_model_bytes is not None
+                       else n_params * 4.0)
+        opt = make_server_optimizer(cfg.server_opt, cfg.server_lr)
+        opt_state = opt.init(params)
+    with spans.span("fl.setup.fleet"):
+        pop, sim_steps, up_bytes, energy_model = _engine_setup(
+            cfg, kpop, model_bytes)
     return (kloop, data, test, params, opt_state, pop, sim_steps, up_bytes,
             energy_model, model_bytes)
 
@@ -941,7 +957,12 @@ def _reject_async_knobs(cfg: FLConfig, name: str) -> None:
 def _history_from_traj(cfg: FLConfig, init_acc: float, traj) -> FLHistory:
     """Assemble :class:`FLHistory` from a fused-engine trajectory. The only
     host float work is the f64 wall-clock accumulation, done exactly like
-    the host loop (per-round /3600 then cumulative sum)."""
+    the host loop (per-round /3600 then cumulative sum).
+
+    Counts, in the open span, the trajectory's cohort local SGD:
+    ``sgd.slots_trained``, every slot of every round (the fused engines
+    train dead slots too), and ``sgd.slots_aggregated``, the slots whose
+    delta reached an applied server update."""
     hist = FLHistory(init_acc=init_acc)
     dur = np.asarray(traj["round_duration"])
     hist.round = list(range(1, cfg.rounds + 1))
@@ -956,6 +977,10 @@ def _history_from_traj(cfg: FLConfig, init_acc: float, traj) -> FLHistory:
     n_sel = np.asarray(traj["chosen"]).sum(axis=1).astype(np.float64)
     hist.participation = [float(x) for x in
                           n_succ / np.maximum(n_sel, 1.0)]
+    aggregated = ((n_succ - np.asarray(traj["quarantined"]))
+                  * (1 - np.asarray(traj["update_skipped"])))
+    spans.count("sgd.slots_trained", np.asarray(traj["succeeded"]).size)
+    spans.count("sgd.slots_aggregated", aggregated.sum())
     # train_loss: reduce the compacted per-slot losses with the SAME jnp
     # f32 mean the host loop uses (`mean_losses.mean()` over the dynamic
     # cohort) — reducing in-scan over the fixed slot axis would associate
@@ -1016,13 +1041,22 @@ def _fused_do_eval(cfg: FLConfig, a: int, b: int) -> jnp.ndarray:
     return jax.device_put(((rr % cfg.eval_every) == 0) | (rr == cfg.rounds))
 
 
-def _run_fused_elastic(cfg: FLConfig, run, carry0, run_args,
-                       resume_templates, save_state, meta=None,
+def _untrained_acc(evaluate, params, test) -> Tuple[jnp.ndarray, float]:
+    """The untrained model's test accuracy, on the device (the carry's
+    first ``last_acc``) and on the host (the history's ``init_acc``)."""
+    with spans.span("fl.setup.eval0"):
+        acc0 = evaluate(params, test["x"], test["y"])
+        return acc0, float(jax.device_get(acc0))
+
+
+def _run_fused_elastic(cfg: FLConfig, run, carry0, init_acc: float,
+                       run_args, resume_templates, save_state, meta=None,
                        history_fn=None, carry_names=_TRAIN_CARRY,
                        capture=None) -> FLHistory:
     """Shared segment/checkpoint/resume driver for the fused training
     engines (sync scanned/sharded and their async twins). ``carry0`` is
-    the fresh carry tuple laid out as ``carry_names``; ``run_args`` the
+    the fresh carry tuple laid out as ``carry_names``, ``init_acc`` its
+    untrained accuracy (a resumed run takes the saved one); ``run_args`` the
     engine's per-call data tail; ``resume_templates["restore"](state)``
     maps loaded checkpoint state back onto an engine carry (with
     ``resume_templates["pop_template"]`` as the unpadded population
@@ -1031,7 +1065,9 @@ def _run_fused_elastic(cfg: FLConfig, run, carry0, run_args,
     ``save_state(carry)`` maps a live carry to the (engine-portable)
     checkpoint state dict. ``meta``/``history_fn`` default to the
     synchronous family; ``capture``, when a dict, receives the full
-    concatenated trajectory under ``"traj"`` (parity-test hook)."""
+    concatenated trajectory under ``"traj"`` (parity-test hook). The
+    segments run in the span ``fl.scan``, ``history_fn`` in
+    ``fl.history``."""
     if meta is None:
         meta = _train_meta(cfg, "train-sync")
     if history_fn is None:
@@ -1052,18 +1088,19 @@ def _run_fused_elastic(cfg: FLConfig, run, carry0, run_args,
     else:
         start = 0
         carry = carry0
-        init_acc = float(jax.device_get(
-            carry0[carry_names.index("last_acc")]))
-    for a, b in segment_bounds(start, cfg.rounds, ck.every if ck else None):
-        carry, traj = run(_fused_do_eval(cfg, a, b), carry, *run_args)
-        parts.append(jax.device_get(traj))
-        if ck and ck.due(b):
-            ck.save(b, save_state(carry),
-                    {"traj": _concat_traj(parts), "init_acc": init_acc})
+    with spans.span("fl.scan"):
+        for a, b in segment_bounds(start, cfg.rounds,
+                                   ck.every if ck else None):
+            carry, traj = run(_fused_do_eval(cfg, a, b), carry, *run_args)
+            parts.append(jax.device_get(traj))
+            if ck and ck.due(b):
+                ck.save(b, save_state(carry),
+                        {"traj": _concat_traj(parts), "init_acc": init_acc})
     traj = _concat_traj(parts)
     if capture is not None:
         capture["traj"] = traj
-    return history_fn(cfg, init_acc, traj)
+    with spans.span("fl.history"):
+        return history_fn(cfg, init_acc, traj)
 
 
 def run_fl_scanned(cfg: FLConfig, verbose: bool = False) -> FLHistory:
@@ -1079,20 +1116,24 @@ def run_fl_scanned(cfg: FLConfig, verbose: bool = False) -> FLHistory:
     because the RNG chain rides in the scan carry, the segmented (and the
     resumed) trajectory is bitwise-identical to the uninterrupted one."""
     _reject_async_knobs(cfg, "run_fl_scanned")
-    with setup_transfers():  # one-time host->device materialization
+    # one-time host->device materialization
+    with setup_transfers(), spans.span("fl.setup"):
         (kloop, data, test, params, opt_state, pop, sim_steps, up_bytes,
          energy_model, model_bytes) = _fused_setup(cfg)
-        t_total, cost = round_cost_table(pop, energy_model, model_bytes,
-                                         sim_steps, cfg.batch_size, up_bytes)
-        run, evaluate = _fused_runner(cfg.model, *_fused_statics(cfg),
-                                      _auto_pallas(cfg.n_clients, None),
-                                      jax.default_backend() != "tpu")
-        st = SelectorState.create(cfg.selector).canonical()
-        acc0 = evaluate(params, test["x"], test["y"])
+        with spans.span("fl.setup.cost_table"):
+            t_total, cost = round_cost_table(pop, energy_model, model_bytes,
+                                             sim_steps, cfg.batch_size,
+                                             up_bytes)
+        with spans.span("fl.setup.runner"):
+            run, evaluate = _fused_runner(cfg.model, *_fused_statics(cfg),
+                                          _auto_pallas(cfg.n_clients, None),
+                                          jax.default_backend() != "tpu")
+            st = SelectorState.create(cfg.selector).canonical()
+        acc0, init_acc = _untrained_acc(evaluate, params, test)
         carry0 = (params, opt_state, pop, st, kloop, acc0,
                   BudgetLedger.create())
     hist = _run_fused_elastic(
-        cfg, run, carry0,
+        cfg, run, carry0, init_acc,
         (data["x"], data["y"], test["x"], test["y"], t_total, cost),
         {"pop_template": pop,
          "restore": lambda state: tuple(state[k] for k in _TRAIN_CARRY)},
@@ -1297,14 +1338,12 @@ def _sharded_fused_runner(model_cfg: ResNetConfig, sel_cfg: SelectorConfig,
 
     @jax.jit
     def evaluate(params, test_x, test_y):
-        logits = resnet_forward(model_cfg, params, test_x)
-        return (jnp.argmax(logits, -1) == test_y).mean()
+        return _test_accuracy(model_cfg, params, test_x, test_y)
 
     @functools.partial(jax.jit, donate_argnums=(1,))
     def run(do_eval, carry, data_x, data_y, test_x, test_y, t_total, cost):
         def eval_acc(p):
-            logits = resnet_forward(model_cfg, p, test_x)
-            return (jnp.argmax(logits, -1) == test_y).mean()
+            return _test_accuracy(model_cfg, p, test_x, test_y)
 
         shard = NamedSharding(mesh, spec)
 
@@ -1362,7 +1401,8 @@ def run_fl_sharded(cfg: FLConfig, verbose: bool = False, mesh=None,
     if mesh is None:
         mesh = make_client_mesh(n_shards)
     axis_name = mesh.axis_names[0]
-    with setup_transfers():  # one-time host->device materialization
+    # one-time host->device materialization
+    with setup_transfers(), spans.span("fl.setup"):
         (kloop, data, test, params, opt_state, pop, sim_steps, up_bytes,
          energy_model, model_bytes) = _fused_setup(cfg)
         n_real = pop.n
@@ -1379,14 +1419,16 @@ def run_fl_sharded(cfg: FLConfig, verbose: bool = False, mesh=None,
             return jax.device_put(a, sharding)
 
         data_x, data_y = pad_clients(data["x"]), pad_clients(data["y"])
-        t_total, cost = round_cost_table(pop, energy_model, model_bytes,
-                                         sim_steps, cfg.batch_size,
-                                         up_bytes, sharding=sharding)
-        run, evaluate = _sharded_fused_runner(
-            cfg.model, *_fused_statics(cfg), _auto_pallas(n_real, None),
-            jax.default_backend() != "tpu", mesh, n_real, axis_name)
-        st = SelectorState.create(cfg.selector).canonical()
-        acc0 = evaluate(params, test["x"], test["y"])
+        with spans.span("fl.setup.cost_table"):
+            t_total, cost = round_cost_table(pop, energy_model, model_bytes,
+                                             sim_steps, cfg.batch_size,
+                                             up_bytes, sharding=sharding)
+        with spans.span("fl.setup.runner"):
+            run, evaluate = _sharded_fused_runner(
+                cfg.model, *_fused_statics(cfg), _auto_pallas(n_real, None),
+                jax.default_backend() != "tpu", mesh, n_real, axis_name)
+            st = SelectorState.create(cfg.selector).canonical()
+        acc0, init_acc = _untrained_acc(evaluate, params, test)
         carry0 = (params, opt_state, pop, st, kloop, acc0,
                   BudgetLedger.create())
 
@@ -1406,7 +1448,7 @@ def run_fl_sharded(cfg: FLConfig, verbose: bool = False, mesh=None,
         return s
 
     hist = _run_fused_elastic(
-        cfg, run, carry0,
+        cfg, run, carry0, init_acc,
         (data_x, data_y, test["x"], test["y"], t_total, cost),
         {"pop_template": pop0, "restore": _restore},
         _save_state)

@@ -28,6 +28,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import spans
 from repro.checkpoint import (CarryCheckpointer, load_engine_checkpoint,
                               segment_bounds)
 from repro.core.clients import ClientPopulation, pad_population, round_times
@@ -167,6 +168,7 @@ def _aany(x, axis_name):
     return a
 
 
+@jax.named_scope("energy_sim")
 def simulate_round_device(pop: ClientPopulation, sel_mask: jnp.ndarray,
                           t_total: jnp.ndarray, cost: jnp.ndarray,
                           rnd, energy_model: EnergyModel,
@@ -1847,6 +1849,7 @@ def resolve_engine(n: int, device_count: Optional[int] = None, *,
     return "sharded" if sharded else "scanned"
 
 
+@spans.span("run_rounds")
 def run_rounds(key, sel_cfg: SelectorConfig, pop: ClientPopulation,
                sel_state: SelectorState, energy_model: EnergyModel,
                model_bytes: float, local_steps: int, batch_size: int,
