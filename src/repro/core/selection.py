@@ -11,9 +11,10 @@ which EAFL modifies *only* in the reward definition, Eq. 1):
     system-efficiency penalty in Eq. 2.
 
 The hot path is device-resident: ``select_device`` is a single jitted
-function (exploration via the Gumbel-top-k trick, exploitation via
-``jax.lax.top_k`` or, above ``PALLAS_N_THRESHOLD`` on TPU, the fused
-Pallas ``topk_reward`` kernel), returning fixed-shape ``(k,)`` indices plus
+function (exploration via the Gumbel-top-k trick, exploitation on the
+score; both top-ks via ``jax.lax.top_k`` or, above ``PALLAS_N_THRESHOLD``
+on TPU, the pruned Pallas block top-k of ``kernels.topk_select``, fused
+with the reward for exploitation), returning fixed-shape ``(k,)`` indices plus
 a chosen-slot mask so it composes with ``jax.lax.scan``. ``select`` is the
 thin host wrapper that trims to the chosen slots; ``select_host`` keeps the
 original eager numpy implementation as the parity reference.
@@ -50,6 +51,14 @@ if "JAX_THREEFRY_PARTITIONABLE" not in _os.environ:
 # population size above which the Pallas kernel is preferred on TPU;
 # below it a single lax.top_k is faster than a two-level tournament.
 PALLAS_N_THRESHOLD = 131_072
+
+
+def _top_idx(x, k: int, use_pallas: bool, interpret: bool) -> jnp.ndarray:
+    """``lax.top_k(x, k)``'s indices, through the pruned Pallas kernel
+    where ``use_pallas``."""
+    if use_pallas:
+        return _tk.topk_scores(x, k, interpret=interpret)[1]
+    return jax.lax.top_k(x, k)[1]
 
 
 @dataclass(frozen=True)
@@ -218,7 +227,7 @@ def _device_select(key, cfg: SelectorConfig, state: SelectorState,
 
     if cfg.kind == "random":
         g = jnp.where(valid, _rank_bits(key, n), -1.0)
-        _, idx = jax.lax.top_k(g, k)
+        idx = _top_idx(g, k, use_pallas, interpret)
         return idx.astype(jnp.int32), slots < k_eff, state
 
     explored = pop.explored & valid
@@ -248,7 +257,7 @@ def _device_select(key, cfg: SelectorConfig, state: SelectorState,
         _, exploit_idx = jax.lax.top_k(score, k)
 
     g = jnp.where(unexplored, _rank_bits(key, n), -1.0)
-    _, explore_idx = jax.lax.top_k(g, k)
+    explore_idx = _top_idx(g, k, use_pallas, interpret)
 
     take_exploit = slots < n_exploit
     idx = jnp.where(take_exploit, exploit_idx,

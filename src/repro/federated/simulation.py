@@ -45,6 +45,7 @@ from repro.core.selection import (
 )
 from repro.federated.faults import (N_FAULT_STREAMS, FaultConfig, apply_faults,
                                     fault_streams, faults_for_round)
+from repro.kernels.topk_select import PickTally, pick_tally
 
 
 @dataclass
@@ -149,6 +150,17 @@ def predicted_round_cost_pct(pop: ClientPopulation, energy_model: EnergyModel,
     """battery_used(i) for Eq. 1's power(i) — identical model to the debit."""
     return _round_cost(pop, energy_model, model_bytes, local_steps,
                        batch_size, up_bytes)[1]
+
+
+def _picks_out(tally: PickTally, axis_name: Optional[str] = None
+               ) -> Dict[str, jnp.ndarray]:
+    """A step's Pallas top-k pick counts as trajectory entries, summed
+    over the shards of ``axis_name`` (zero where ``lax.top_k`` ran)."""
+    picks, slots = tally.picks, jnp.int32(tally.slots)
+    if axis_name is not None and tally.slots:
+        picks = jax.lax.psum(picks, axis_name)
+        slots = jax.lax.psum(slots, axis_name)
+    return {"topk_picks": picks, "topk_pick_slots": slots}
 
 
 def _asum(x, axis_name):
@@ -402,13 +414,14 @@ def _scanned_runner(sel_cfg: SelectorConfig, energy_model: EnergyModel,
 
     def scan_step(carry, key_r):
         pop, st = carry
-        if faulty:
-            pop, st, idx, chosen, dev, retries, corrupt = step(key_r, pop,
-                                                               st)
-        else:
-            pop, st, idx, chosen, dev = step(key_r, pop, st)
-            retries = jnp.int32(0)
-            corrupt = jnp.zeros((pop.n,), bool)
+        with pick_tally() as tally:
+            if faulty:
+                pop, st, idx, chosen, dev, retries, corrupt = step(key_r,
+                                                                   pop, st)
+            else:
+                pop, st, idx, chosen, dev = step(key_r, pop, st)
+                retries = jnp.int32(0)
+                corrupt = jnp.zeros((pop.n,), bool)
         out = {
             "selected": idx,
             "chosen": chosen,
@@ -421,6 +434,7 @@ def _scanned_runner(sel_cfg: SelectorConfig, energy_model: EnergyModel,
             "total_dropped": jnp.sum(pop.dropped).astype(jnp.int32),
             "retries": retries,
             "corrupt": corrupt[idx] & chosen,
+            **_picks_out(tally),
         }
         return (pop, st), out
 
@@ -504,7 +518,9 @@ def run_rounds_scanned(key, sel_cfg: SelectorConfig, pop: ClientPopulation,
     ``new_dropouts (R,)``, ``energy_spent_pct (R,)``, ``mean_battery (R,)``,
     ``total_dropped (R,)``, plus the fault-injection bookkeeping
     ``retries (R,)`` and ``corrupt (R,k)`` (all-zero unless ``faults`` is
-    active).
+    active), and ``topk_picks (R,)`` / ``topk_pick_slots (R,)``: the serial
+    picks the round's Pallas top-k calls made and what the unpruned kernel
+    would make (zero where ``lax.top_k`` runs; every engine carries them).
 
     Elasticity: ``checkpoint_path`` (+ ``checkpoint_every`` rounds, default
     final-only) atomically snapshots the full scan carry + trajectory
@@ -652,13 +668,14 @@ def _sharded_scanned_runner(sel_cfg: SelectorConfig,
     faulty = faults is not None and faults.active
 
     def body(key_r, st, pop, t_total, cost, bits, streams=None):
-        (pop, st, idx, chosen, succ_sel, dev, retries, corrupt_sel,
-         _admit, _ledger) = _shard_round_step(
-            key_r, st, pop, t_total, cost, bits, sel_cfg=sel_cfg,
-            energy_model=energy_model, deadline_s=deadline_s,
-            use_pallas=use_pallas, interpret=interpret,
-            axis_name=axis_name, n_real=n_real,
-            faults=faults if faulty else None, streams=streams)
+        with pick_tally() as tally:
+            (pop, st, idx, chosen, succ_sel, dev, retries, corrupt_sel,
+             _admit, _ledger) = _shard_round_step(
+                key_r, st, pop, t_total, cost, bits, sel_cfg=sel_cfg,
+                energy_model=energy_model, deadline_s=deadline_s,
+                use_pallas=use_pallas, interpret=interpret,
+                axis_name=axis_name, n_real=n_real,
+                faults=faults if faulty else None, streams=streams)
         out = {
             "selected": idx,
             "chosen": chosen,
@@ -672,6 +689,7 @@ def _sharded_scanned_runner(sel_cfg: SelectorConfig,
                               .astype(jnp.int32) - n_pad),
             "retries": retries,
             "corrupt": corrupt_sel,
+            **_picks_out(tally, axis_name),
         }
         return pop, st, out
 
@@ -1006,8 +1024,9 @@ def _async_scanned_runner(sel_cfg: SelectorConfig, energy_model: EnergyModel,
 
     def scan_step(carry, xs):
         pop, st, astate = carry
-        pop, st, astate, flush, (ridx, rchosen) = step(
-            xs["key"], pop, st, astate, xs["refill"])
+        with pick_tally() as tally:
+            pop, st, astate, flush, (ridx, rchosen) = step(
+                xs["key"], pop, st, astate, xs["refill"])
         out = {
             **flush,
             "selected": ridx,
@@ -1018,6 +1037,7 @@ def _async_scanned_runner(sel_cfg: SelectorConfig, energy_model: EnergyModel,
             "total_dropped": jnp.sum(pop.dropped).astype(jnp.int32),
             "budget_spent_j": astate.spent_j,
             "budget_exhausted": astate.exhausted_round,
+            **_picks_out(tally),
         }
         return (pop, st, astate), out
 
@@ -1366,7 +1386,8 @@ def _shard_async_step(key, sel_state, astate, pop, t_total, cost, bits,
     per-client arithmetic is elementwise on this shard's slice (bitwise
     identical to the unsharded run), and the only cross-shard traffic is
     the flush/refill candidate merges, the one-owner-per-slot gathers for
-    staleness/success, and the scalar psum/pmax round stats.
+    staleness/success, and the scalar psum/pmax round stats. ``stats``
+    also holds the refill's Pallas top-k pick counts, summed over shards.
     """
     n_loc = cost.shape[0]
     base = (jax.lax.axis_index(axis_name) * n_loc).astype(jnp.int32)
@@ -1425,10 +1446,11 @@ def _shard_async_step(key, sel_state, astate, pop, t_total, cost, bits,
 
     # ---- refill the freed slots ----------------------------------------
     sel_pop = pop.replace(dropped=pop.dropped | astate.in_flight)
-    ridx, rchosen, new_sel_state = _shard_select(
-        key, sel_state, sel_pop, cost, bits, cfg=refill_cfg,
-        axis_name=axis_name, n_real=n_real, use_pallas=use_pallas,
-        interpret=interpret)
+    with pick_tally() as tally:
+        ridx, rchosen, new_sel_state = _shard_select(
+            key, sel_state, sel_pop, cost, bits, cfg=refill_cfg,
+            axis_name=axis_name, n_real=n_real, use_pallas=use_pallas,
+            interpret=interpret)
     rchosen = rchosen & do_refill
     rchosen, astate = _shard_admit_batch(astate, pop, cost, ridx, rchosen,
                                          astate.server_version + 1,
@@ -1446,6 +1468,7 @@ def _shard_async_step(key, sel_state, astate, pop, t_total, cost, bits,
                           .astype(jnp.int32) - n_pad),
         "budget_spent_j": astate.spent_j,
         "budget_exhausted": astate.exhausted_round,
+        **_picks_out(tally, axis_name),
     }
     return pop, sel_state, astate, flush, (ridx, rchosen), stats
 
@@ -1886,7 +1909,10 @@ def run_rounds(key, sel_cfg: SelectorConfig, pop: ClientPopulation,
     == k, staleness_power=0`` limit, so every dispatch decision is
     behavior-preserving on the same key (the parity contracts of the
     underlying engines). The chosen engine name is recorded in the
-    returned trajectory as ``traj["engine"]``.
+    returned trajectory as ``traj["engine"]``, and the span ``run_rounds``
+    counts the trajectory's ``topk.picks`` and ``topk.pick_slots`` (the
+    async engines' initial fill is not a trajectory row and not counted)
+    without waiting for the device.
 
     Elasticity + faults pass through to every engine: ``faults`` injects
     deterministic seed-driven transient client faults (sync engines only),
@@ -1942,5 +1968,7 @@ def run_rounds(key, sel_cfg: SelectorConfig, pop: ClientPopulation,
     else:
         fpop, st, traj = run_async_sharded(*args, **common, **async_kw,
                                            mesh=mesh, n_shards=n_shards)
+    spans.count("topk.picks", traj["topk_picks"])
+    spans.count("topk.pick_slots", traj["topk_pick_slots"])
     traj["engine"] = engine
     return fpop, st, traj

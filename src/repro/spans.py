@@ -9,6 +9,9 @@ its events with) and a dict of counts. It also opens a
 trace shows each phase on the host's timeline beside the device's
 operations. ``count(key, n)`` adds to the innermost open span; a closing
 span adds its counts to its parent's, so a root holds the whole call's.
+``n`` may be an array, whose elements are summed; a JAX array is kept as
+it is and summed when ``recent()`` is read, so counting a value the
+device computes does not wait for the device.
 
 Closed spans go into a bounded in-memory record that ``recent()`` returns,
 oldest first. Nothing is written anywhere: the profiler trace is the
@@ -29,13 +32,38 @@ import itertools
 import threading
 import time
 from dataclasses import dataclass, field
-from typing import Deque, Dict, Iterator, List, Optional
+from typing import Deque, Dict, Iterator, List, Optional, Tuple
 
 import jax
+import numpy as np
 
 RECENT_MAX = 4096
 _COMPILE = "/jax/core/compile/backend_compile_duration"
 _CACHE_HIT = "/jax/compilation_cache/cache_hits"
+
+
+@dataclass(frozen=True)
+class _Deferred:
+    """A count that holds JAX arrays: ``base`` plus the sum of their
+    elements, resolved when the record is read."""
+
+    base: int
+    arrays: Tuple[jax.Array, ...]
+
+    def resolve(self) -> int:
+        return self.base + sum(int(np.asarray(a).sum())
+                               for a in self.arrays)
+
+
+def _sum(old, n):
+    """``old + n`` for counts: ints, arrays, or deferred sums."""
+    if isinstance(n, jax.Array):
+        n = _Deferred(0, (n,))
+    if isinstance(old, _Deferred) or isinstance(n, _Deferred):
+        a, b = (x if isinstance(x, _Deferred) else _Deferred(x, ())
+                for x in (old, n))
+        return _Deferred(a.base + b.base, a.arrays + b.arrays)
+    return old + int(np.sum(n))
 
 
 @dataclass
@@ -69,7 +97,7 @@ def count(key: str, n: int = 1) -> None:
     stack = _open()
     if stack:
         c = stack[-1].counts
-        c[key] = c.get(key, 0) + int(n)
+        c[key] = _sum(c.get(key, 0), n)
 
 
 def _on_duration(event: str, duration: float, **_) -> None:
@@ -111,10 +139,15 @@ def span(name: str) -> Iterator[Span]:
         stack.pop()
         if parent is not None:
             for k, v in s.counts.items():
-                parent.counts[k] = parent.counts.get(k, 0) + v
+                parent.counts[k] = _sum(parent.counts.get(k, 0), v)
         _recent.append(s)
 
 
 def recent() -> Deque[Span]:
-    """The last ``RECENT_MAX`` closed spans, in the order they closed."""
+    """The last ``RECENT_MAX`` closed spans, in the order they closed,
+    every count resolved to an int."""
+    for s in _recent:
+        for k, v in s.counts.items():
+            if isinstance(v, _Deferred):
+                s.counts[k] = v.resolve()
     return _recent

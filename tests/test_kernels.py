@@ -5,6 +5,7 @@ import numpy as np
 import pytest
 
 from repro.kernels import ops, ref
+from repro.kernels import topk_select as tk
 
 
 # ----------------------------------------------------------- flash attention
@@ -56,17 +57,96 @@ def test_selective_scan(shape, dtype, rng):
 
 
 # ------------------------------------------------------------- top-k reward
-@pytest.mark.parametrize("n,k,block", [(1024, 10, 256), (4096, 32, 1024),
-                                       (2048, 1, 512), (8192, 64, 4096)])
-def test_topk_reward(n, k, block, rng):
-    util = jax.random.normal(jax.random.fold_in(rng, 0), (n,))
-    power = jax.random.normal(jax.random.fold_in(rng, 1), (n,))
-    valid = jax.random.bernoulli(jax.random.fold_in(rng, 2), 0.8, (n,))
-    tv, ti = ops.topk_reward(util, power, valid, f=0.25, k=k, block_n=block)
-    ev, ei = ref.topk_reward_ref(util, power, valid, 0.25, k)
-    np.testing.assert_allclose(np.asarray(tv), np.asarray(ev), atol=1e-6)
-    # indices must agree where values are distinct (ties may permute)
-    assert set(np.asarray(ti).tolist()) == set(np.asarray(ei).tolist())
+def _topk_inputs(case, n, key):
+    """``(a, b, valid)`` for a top-k case: iid normals; a coarse grid
+    whose ties straddle the pruning threshold; the whole top-k in one
+    block (the first 1024 entries hold every high score); fewer valid
+    entries than k (five); none valid at all, as in a fleet whose
+    batteries have all run out."""
+    normal = lambda i: jax.random.normal(jax.random.fold_in(key, i), (n,))
+    grid = lambda i: jnp.round(jax.random.uniform(
+        jax.random.fold_in(key, i), (n,)) * 8) / 8
+    valid = jax.random.bernoulli(jax.random.fold_in(key, 2), 0.8, (n,))
+    if case == "iid":
+        return normal(0), normal(1), valid
+    if case == "grid":
+        return grid(0), grid(1), valid
+    if case == "one-block":
+        hot = jnp.arange(n) < 1024
+        return jnp.where(hot, 10.0 + normal(0), normal(0)), normal(1), valid
+    if case == "few-valid":
+        few = jax.random.permutation(jax.random.fold_in(key, 3), n)[:5]
+        return normal(0), normal(1), jnp.zeros((n,), bool).at[few].set(True)
+    if case == "none-valid":
+        return normal(0), normal(1), jnp.zeros((n,), bool)
+    raise ValueError(case)
+
+
+# (n, k, block, inputs, form, picks): blocks round up to 1024 entries;
+# picks "all" = n_blocks * k, the unpruned loop; "pruned" = under 5% of
+# that; "k+valid" = the valid entries and k masked ones at most
+TOPK_CASES = [
+    pytest.param(1024, 10, 256, "iid", "reward", "all", id="1024-10-256"),
+    pytest.param(4096, 32, 1024, "iid", "reward", "all",  # n_blocks < k
+                 id="4096-32-1024"),
+    pytest.param(2048, 1, 512, "iid", "reward", None, id="2048-1-512"),
+    pytest.param(8192, 64, 4096, "iid", "reward", "all", id="8192-64-4096"),
+    pytest.param(65536, 16, 1024, "grid", "reward", None,
+                 id="ties-straddle-t0"),
+    pytest.param(65536, 16, 1024, "one-block", "reward", None,
+                 id="topk-in-one-block"),
+    pytest.param(65536, 16, 1024, "few-valid", "reward", "k+valid",
+                 id="fewer-valid-than-k"),
+    pytest.param(65536, 16, 1024, "none-valid", "reward", "k+valid",
+                 id="none-valid"),
+    pytest.param(1_048_576, 100, 4096, "iid", "reward", "pruned",
+                 id="iid-1M-k100"),
+    pytest.param(65536, 16, 1024, "grid", "scores", None,
+                 id="exploration-ties"),
+    pytest.param(1_048_576, 100, 4096, "iid", "scores", "pruned",
+                 id="exploration-iid-1M-k100"),
+]
+
+
+@pytest.mark.parametrize("n,k,block,inputs,form,picks", TOPK_CASES)
+def test_topk_reward(n, k, block, inputs, form, picks, rng):
+    """The pruned block top-k against ``lax.top_k``, index for index
+    (ties to the lowest index): the fused reward, or the exploration
+    form over ``where(valid, x, -1)``; and the serial picks it made."""
+    util, power, valid = _topk_inputs(inputs, n, rng)
+    x = jnp.where(valid, util, -1.0)
+
+    def run(util, power, valid, x):
+        with tk.pick_tally() as tally:
+            if form == "reward":
+                out = tk.topk_reward(util, power, valid, f=0.25, k=k,
+                                     block_n=block, interpret=True)
+            else:
+                out = tk.topk_scores(x, k, block_n=block, interpret=True)
+        return out + (tally.picks, tally.slots)
+
+    tv, ti, made, slots = (int(o) if o.ndim == 0 else np.asarray(o)
+                           for o in jax.jit(run)(util, power, valid, x))
+    if form == "reward":
+        ev, ei = ref.topk_reward_ref(util, power, valid, 0.25, k)
+    else:
+        ev, ei = jax.lax.top_k(x, k)
+    np.testing.assert_array_equal(ti, np.asarray(ei))
+    # masked picks read SENTINEL where the oracle reads -inf
+    ev = np.asarray(ev)
+    np.testing.assert_array_equal(tv[np.isfinite(ev)], ev[np.isfinite(ev)])
+    assert slots == -(-n // max(block, 1024)) * k
+    if picks == "all":
+        assert made == slots
+    elif picks == "pruned":
+        assert made < 0.05 * slots
+    elif picks == "k+valid":
+        # masked entries are picked in the first block only
+        assert k <= made <= k + int(jnp.sum(valid))
+    if inputs == "one-block":
+        # the hot block holds ~800 entries above t0 but makes k picks;
+        # the k - 1 next blocks by maximum make at least one each
+        assert 2 * k - 1 <= made < 4 * k
 
 
 def test_topk_reward_f_extremes(rng):

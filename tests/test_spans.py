@@ -85,6 +85,22 @@ def test_counts_roll_up_to_the_parent():
     assert a.counts == {"n": 7, "m": 3}
 
 
+def test_a_device_count_is_kept_without_a_transfer_and_read_as_an_int():
+    picks = jnp.arange(5, dtype=jnp.int32)       # on the device
+    three = jnp.asarray(3, jnp.int32)
+    with spans.span("outer") as outer:
+        with jax.transfer_guard_device_to_host("disallow"):
+            with spans.span("inner") as inner:
+                spans.count("picks", picks)
+                spans.count("picks", three)
+                spans.count("picks", 2)
+                spans.count("slots", np.array([4, 4]))
+    rec = spans.recent()
+    assert inner.counts == {"picks": 15, "slots": 8}
+    assert outer.counts == {"picks": 15, "slots": 8}
+    assert all(type(v) is int for s in rec for v in s.counts.values())
+
+
 def test_a_span_left_by_an_exception_is_closed_and_recorded():
     with pytest.raises(RuntimeError):
         with spans.span("outer") as outer:
@@ -243,7 +259,23 @@ def test_run_rounds_is_one_span_without_children():
                EnergyModel(), 85e6, 40, 20, 3)
     by_id, root = _call("run_rounds")
     assert list(by_id) == [root.id]
-    assert set(root.counts) <= {"xla.programs", "xla.cache_loads"}
+    assert set(root.counts) <= {"xla.programs", "xla.cache_loads",
+                                "topk.picks", "topk.pick_slots"}
+    # lax.top_k on the CPU: no Pallas picks, no slots
+    assert root.counts["topk.picks"] == root.counts["topk.pick_slots"] == 0
+
+
+def test_run_rounds_counts_the_pallas_picks():
+    sel = SelectorConfig(kind="eafl", k=4)
+    pop = make_population(jax.random.PRNGKey(3), 20_000)
+    _, _, traj = run_rounds(jax.random.PRNGKey(4), sel, pop,
+                            SelectorState.create(sel), EnergyModel(), 85e6,
+                            40, 20, 3, use_pallas=True, interpret=True)
+    _, root = _call("run_rounds")
+    # two top-ks a round, each over 5 blocks of 4096
+    assert root.counts["topk.pick_slots"] == 3 * 2 * 5 * 4
+    assert root.counts["topk.picks"] == int(np.sum(traj["topk_picks"]))
+    assert 0 < root.counts["topk.picks"] < 3 * 2 * 5 * 4
 
 
 # ------------------------------------------------------- device scopes

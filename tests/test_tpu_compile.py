@@ -1,16 +1,19 @@
 """The selection kernel compiles for a TPU v5e and stays exact.
 
-Compile cases: ``topk_reward`` ahead-of-time compiled for one chip of a
-described (not attached) v5e at the shapes the main path dispatches to
-it — the million-client fleet, the Pallas threshold, an odd population
-(tail padding) and the per-shard leg of the sharded engine (4,194,304
-clients over four chips, traced ``index_offset``). The TPU compiler
-refuses misaligned block shapes and over-budget VMEM that interpret mode
+Compile cases: the exploitation top-k (``topk_reward``) and the
+exploration top-k (``topk_scores``) ahead-of-time compiled for one chip
+of a described (not attached) v5e at the shapes the main path dispatches
+to them — the million-client fleet, FedScale's Reddit fleet (1,660,820),
+the Pallas threshold, an odd population (tail padding) and, for
+exploitation, the per-shard leg of the sharded engine (4,194,304 clients
+over four chips, traced ``index_offset``). The TPU compiler refuses
+misaligned block shapes and over-budget VMEM that interpret mode
 accepts, so these guard the chip path at no chip time.
 
 Interpret cases: the same shapes run through the Pallas interpreter on
-the CPU against ``lax.top_k`` over the unfused score, with heavily tied
-inputs, index for index (ties go to the lowest index).
+the CPU against ``lax.top_k`` over the unfused score (exploration: over
+``where(valid, x, -1)``), with heavily tied inputs, index for index
+(ties go to the lowest index).
 
 The topology is described inside a module fixture, never at import: the
 TPU library may be loaded by one process at a time, and every test worker
@@ -27,12 +30,20 @@ from jax.sharding import SingleDeviceSharding
 from repro.kernels import ref
 from repro.kernels import topk_select as tk
 
-# (n, k, index_offset): fleet, Pallas threshold, odd n, sharded leg
+# (n, k, index_offset, form): fleet, Pallas threshold, odd n, sharded
+# leg, the Reddit fleet; the exploitation and the exploration top-k
 SHAPES = [
-    pytest.param(1_048_576, 100, None, id="fleet-1M-k100"),
-    pytest.param(131_072, 10, None, id="threshold-131072-k10"),
-    pytest.param(150_001, 10, None, id="odd-150001-k10"),
-    pytest.param(1_048_576, 100, 3 * 1_048_576, id="shard-leg-1M-k100"),
+    pytest.param(1_048_576, 100, None, "reward", id="fleet-1M-k100"),
+    pytest.param(131_072, 10, None, "reward", id="threshold-131072-k10"),
+    pytest.param(150_001, 10, None, "reward", id="odd-150001-k10"),
+    pytest.param(1_048_576, 100, 3 * 1_048_576, "reward",
+                 id="shard-leg-1M-k100"),
+    pytest.param(1_660_820, 100, None, "reward", id="reddit-1660820-k100"),
+    pytest.param(131_072, 10, None, "scores",
+                 id="explore-threshold-131072-k10"),
+    pytest.param(150_001, 10, None, "scores", id="explore-odd-150001-k10"),
+    pytest.param(1_660_820, 100, None, "scores",
+                 id="explore-reddit-1660820-k100"),
 ]
 
 
@@ -52,18 +63,22 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.mark.parametrize("n,k,offset", SHAPES)
-def test_topk_reward_compiles_for_v5e(n, k, offset, one_chip):
+@pytest.mark.parametrize("n,k,offset,form", SHAPES)
+def test_topk_reward_compiles_for_v5e(n, k, offset, form, one_chip):
     def select(a, b, valid, ucb, base):
         return tk.topk_reward(a, b, valid, ucb=ucb, f=0.25, k=k,
                               index_offset=None if offset is None else base)
 
     vec = lambda dt: jax.ShapeDtypeStruct((n,), dt, sharding=one_chip)
     base = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
-    compiled = jax.jit(select).lower(
-        vec(jnp.float32), vec(jnp.float32), vec(jnp.int32),
-        vec(jnp.float32), base).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    if form == "reward":
+        lowered = jax.jit(select).lower(
+            vec(jnp.float32), vec(jnp.float32), vec(jnp.int32),
+            vec(jnp.float32), base)
+    else:
+        lowered = jax.jit(lambda x: tk.topk_scores(x, k)).lower(
+            vec(jnp.float32))
+    assert "tpu_custom_call" in lowered.compile().as_text()
 
 
 def _tied_inputs(n, seed=0):
@@ -77,13 +92,20 @@ def _tied_inputs(n, seed=0):
     return a, b, valid, ucb
 
 
-@pytest.mark.parametrize("n,k,offset", SHAPES)
-def test_topk_reward_interpret_matches_lax_top_k(n, k, offset):
+@pytest.mark.parametrize("n,k,offset,form", SHAPES)
+def test_topk_reward_interpret_matches_lax_top_k(n, k, offset, form):
     a, b, valid, ucb = _tied_inputs(n)
-    run = jax.jit(lambda a, b, v, u: tk.topk_reward(
-        a, b, v, ucb=u, f=0.25, k=k, interpret=True, index_offset=offset))
-    tv, ti = run(a, b, valid, ucb)
-    ev, ei = ref.topk_reward_ref(a, b, valid, 0.25, k, ucb=ucb)
+    if form == "reward":
+        run = jax.jit(lambda a, b, v, u: tk.topk_reward(
+            a, b, v, ucb=u, f=0.25, k=k, interpret=True,
+            index_offset=offset))
+        tv, ti = run(a, b, valid, ucb)
+        ev, ei = ref.topk_reward_ref(a, b, valid, 0.25, k, ucb=ucb)
+    else:
+        x = jnp.where(valid, a, -1.0)
+        tv, ti = jax.jit(lambda x: tk.topk_scores(
+            x, k, interpret=True, index_offset=offset))(x)
+        ev, ei = jax.lax.top_k(x, k)
     shift = 0 if offset is None else offset
     np.testing.assert_array_equal(np.asarray(ti), np.asarray(ei) + shift)
     np.testing.assert_array_equal(np.asarray(tv), np.asarray(ev))
